@@ -132,15 +132,17 @@ def _build_route(tab: "TaskTable", P_: int, pp: str, snds, use_ag: bool,
         return jax.lax.dynamic_update_index_in_dim(buf, val, i, 0)
 
     def qwrite(qbuf, slot, val, depth):
-        return wr(qbuf, val, jnp.where(slot < 0, depth, slot))
+        with jax.named_scope("wire"):
+            return wr(qbuf, val, jnp.where(slot < 0, depth, slot))
 
     def sel_from(payload, code_val, want):
         have = [cd for cd in want if cd in snds]
         if not have:
             return None
-        m = functools.reduce(jnp.logical_or,
-                             [code_val == cd for cd in have])
-        return jnp.where(m, payload, jnp.zeros_like(payload))
+        with jax.named_scope("wire"):
+            m = functools.reduce(jnp.logical_or,
+                                 [code_val == cd for cd in have])
+            return jnp.where(m, payload, jnp.zeros_like(payload))
 
     def route_rotations(fq, bq, out, row_all, row):
         snd = row[5]
@@ -167,7 +169,8 @@ def _build_route(tab: "TaskTable", P_: int, pp: str, snds, use_ag: bool,
     def gather_wire(out):
         if P_ == 1:
             return out[None]
-        return jax.lax.all_gather(out, pp, axis=0, tiled=False)
+        with jax.named_scope("exchange"):
+            return jax.lax.all_gather(out, pp, axis=0, tiled=False)
 
     def route_exchange(fq, bq, out, row_all, row):
         outs = gather_wire(out)
@@ -1128,31 +1131,33 @@ def _pack_payload(spec: PipelineSpec, pay: Dict[str, Any],
     per element); the int8 wire quantizes per row with a symmetric
     scale ``amax/127`` carried in two leading uint16 words (an fp32
     bitcast), element pairs bitcast into single words."""
-    B = spec.mbB
-    wire = _wire_of(spec)
-    parts = []
-    for key, shape, dt in _payload_struct(spec, S):
-        a = pay[key]
-        if _leaf_exact(key, dt, wire):
-            w = jax.lax.bitcast_convert_type(a, jnp.uint16)
-            if key == "aux":
-                w = jnp.broadcast_to(w.reshape(1, -1), (B, w.size))
-            else:
-                w = w.reshape(B, -1)
-        elif wire == "bf16":
-            w = jax.lax.bitcast_convert_type(
-                a.astype(jnp.bfloat16), jnp.uint16).reshape(B, -1)
-        else:                                   # int8
-            flat = a.reshape(B, -1).astype(jnp.float32)
-            scale = jnp.maximum(jnp.max(jnp.abs(flat), axis=1,
-                                        keepdims=True), 1e-30) / 127.0
-            q = jnp.clip(jnp.round(flat / scale), -127, 127)
-            qw = jax.lax.bitcast_convert_type(
-                q.astype(jnp.int8).reshape(B, -1, 2), jnp.uint16)
-            sw = jax.lax.bitcast_convert_type(scale, jnp.uint16)
-            w = jnp.concatenate([sw.reshape(B, 2), qw], axis=1)
-        parts.append(w)
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+    with jax.named_scope("wire"):
+        B = spec.mbB
+        wire = _wire_of(spec)
+        parts = []
+        for key, shape, dt in _payload_struct(spec, S):
+            a = pay[key]
+            if _leaf_exact(key, dt, wire):
+                w = jax.lax.bitcast_convert_type(a, jnp.uint16)
+                if key == "aux":
+                    w = jnp.broadcast_to(w.reshape(1, -1), (B, w.size))
+                else:
+                    w = w.reshape(B, -1)
+            elif wire == "bf16":
+                w = jax.lax.bitcast_convert_type(
+                    a.astype(jnp.bfloat16), jnp.uint16).reshape(B, -1)
+            else:                                   # int8
+                flat = a.reshape(B, -1).astype(jnp.float32)
+                scale = jnp.maximum(jnp.max(jnp.abs(flat), axis=1,
+                                            keepdims=True), 1e-30) / 127.0
+                q = jnp.clip(jnp.round(flat / scale), -127, 127)
+                qw = jax.lax.bitcast_convert_type(
+                    q.astype(jnp.int8).reshape(B, -1, 2), jnp.uint16)
+                sw = jax.lax.bitcast_convert_type(scale, jnp.uint16)
+                w = jnp.concatenate([sw.reshape(B, 2), qw], axis=1)
+            parts.append(w)
+        return parts[0] if len(parts) == 1 \
+            else jnp.concatenate(parts, axis=1)
 
 
 def _unpack_payload(spec: PipelineSpec, flat: jnp.ndarray,
@@ -1161,37 +1166,38 @@ def _unpack_payload(spec: PipelineSpec, flat: jnp.ndarray,
     dequantizing for compressed ones.  Forward and backward branches
     both read the *stored wire bytes*, so the chunk pullback linearizes
     at exactly the (dequantized) primal point the forward consumed."""
-    B = spec.mbB
-    wire = _wire_of(spec)
-    out: Dict[str, Any] = {}
-    off = 0
-    for key, shape, dt in _payload_struct(spec, S):
-        ws = jnp.dtype(dt).itemsize // 2
-        if key == "aux":
-            n = int(np.prod(shape)) * ws
-            seg = flat[0:1, off:off + n]
-            out[key] = jax.lax.bitcast_convert_type(
-                seg.reshape(shape + ((ws,) if ws > 1 else ())), dt)
-        elif _leaf_exact(key, dt, wire):
-            n = int(np.prod(shape)) * ws // B
-            seg = flat[:, off:off + n]
-            out[key] = jax.lax.bitcast_convert_type(
-                seg.reshape(shape + ((ws,) if ws > 1 else ())), dt)
-        elif wire == "bf16":
-            n = int(np.prod(shape)) // B
-            seg = flat[:, off:off + n]
-            out[key] = jax.lax.bitcast_convert_type(
-                seg, jnp.bfloat16).reshape(shape).astype(dt)
-        else:                                   # int8
-            elts = int(np.prod(shape)) // B
-            n = 2 + elts // 2
-            seg = flat[:, off:off + n]
-            scale = jax.lax.bitcast_convert_type(
-                seg[:, 0:2].reshape(B, 1, 2), jnp.float32)
-            q = jax.lax.bitcast_convert_type(seg[:, 2:], jnp.int8)
-            x = q.astype(jnp.float32).reshape(B, elts) * scale
-            out[key] = x.reshape(shape).astype(dt)
-        off += n
+    with jax.named_scope("wire"):
+        B = spec.mbB
+        wire = _wire_of(spec)
+        out: Dict[str, Any] = {}
+        off = 0
+        for key, shape, dt in _payload_struct(spec, S):
+            ws = jnp.dtype(dt).itemsize // 2
+            if key == "aux":
+                n = int(np.prod(shape)) * ws
+                seg = flat[0:1, off:off + n]
+                out[key] = jax.lax.bitcast_convert_type(
+                    seg.reshape(shape + ((ws,) if ws > 1 else ())), dt)
+            elif _leaf_exact(key, dt, wire):
+                n = int(np.prod(shape)) * ws // B
+                seg = flat[:, off:off + n]
+                out[key] = jax.lax.bitcast_convert_type(
+                    seg.reshape(shape + ((ws,) if ws > 1 else ())), dt)
+            elif wire == "bf16":
+                n = int(np.prod(shape)) // B
+                seg = flat[:, off:off + n]
+                out[key] = jax.lax.bitcast_convert_type(
+                    seg, jnp.bfloat16).reshape(shape).astype(dt)
+            else:                                   # int8
+                elts = int(np.prod(shape)) // B
+                n = 2 + elts // 2
+                seg = flat[:, off:off + n]
+                scale = jax.lax.bitcast_convert_type(
+                    seg[:, 0:2].reshape(B, 1, 2), jnp.float32)
+                q = jax.lax.bitcast_convert_type(seg[:, 2:], jnp.int8)
+                x = q.astype(jnp.float32).reshape(B, elts) * scale
+                out[key] = x.reshape(shape).astype(dt)
+            off += n
     return out
 
 
@@ -1320,12 +1326,15 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
 
         def embed_core(shared_p, tok, patch, frames):
             counts["embed"] += 1
-            return vary(_embed_tokens(spec, shared_p, tok, patch, frames))
+            with jax.named_scope("embed"):
+                return vary(_embed_tokens(spec, shared_p, tok, patch,
+                                          frames))
 
         def head_core(pay_out, shared_p, labels, mask):
             counts["head"] += 1
-            return to_varying(_head_loss(spec, shared_p, pay_out, labels,
-                                         mask))
+            with jax.named_scope("head_loss"):
+                return to_varying(_head_loss(spec, shared_p, pay_out,
+                                             labels, mask))
 
         jchunk = _traced_once(chunk_core)
         jembed = _traced_once(embed_core)
@@ -1347,11 +1356,14 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
         jcore = _traced_once(fwd_core)
 
         def zero_gs():
-            return vary(jax.tree.map(
-                lambda a: jnp.zeros(a.shape, jnp.float32), shared))
+            with jax.named_scope("grad_accum"):
+                return vary(jax.tree.map(
+                    lambda a: jnp.zeros(a.shape, jnp.float32), shared))
 
-        zero_wire = to_varying(jnp.zeros((spec.mbB, Wb), jnp.uint16))
-        zero_blocks_g = jax.tree.map(jnp.zeros_like, blocks)
+        with jax.named_scope("wire"):
+            zero_wire = to_varying(jnp.zeros((spec.mbB, Wb), jnp.uint16))
+        with jax.named_scope("grad_accum"):
+            zero_blocks_g = jax.tree.map(jnp.zeros_like, blocks)
 
         def pin_buf(a):
             """Packed rings are [slots, mbB, W]: batch over dp."""
@@ -1360,8 +1372,9 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
             return a
 
         def ring(slots, trash):
-            return pin_buf(jnp.zeros((slots + (1 if trash else 0),
-                                      spec.mbB, Wb), jnp.uint16))
+            with jax.named_scope("ring"):
+                return pin_buf(jnp.zeros((slots + (1 if trash else 0),
+                                          spec.mbB, Wb), jnp.uint16))
 
         def carry_init():
             carry = {
@@ -1381,10 +1394,13 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
             return carry
 
         def rd(buf, i):
-            return jax.lax.dynamic_index_in_dim(buf, i, 0, keepdims=False)
+            with jax.named_scope("ring"):
+                return jax.lax.dynamic_index_in_dim(buf, i, 0,
+                                                    keepdims=False)
 
         def wr(buf, val, i):
-            return jax.lax.dynamic_update_index_in_dim(buf, val, i, 0)
+            with jax.named_scope("ring"):
+                return jax.lax.dynamic_update_index_in_dim(buf, val, i, 0)
 
         def tick_core(carry, row_all, codes):
             row = row_all[s_idx]                   # [16]
@@ -1401,11 +1417,14 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 if remat else None
 
             def blocks_at():
-                blocks_c = [jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, c, 0, False),
-                    t_) for t_ in blocks]
-                flags_c = {k: jax.lax.dynamic_index_in_dim(vv, c, 0, False)
-                           for k, vv in flags.items()}
+                with jax.named_scope("ring"):
+                    blocks_c = [jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(a, c, 0,
+                                                               False),
+                        t_) for t_ in blocks]
+                    flags_c = {k: jax.lax.dynamic_index_in_dim(vv, c, 0,
+                                                               False)
+                               for k, vv in flags.items()}
                 return blocks_c, flags_c
 
             def batch_inputs():
@@ -1420,15 +1439,18 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 return tok_in, patch, frames, labels, mask
 
             def bnd_read(carry):
-                a = rd(carry["act"], gact)
-                if remat:
-                    a = jnp.where(rslot >= 0, rd(carry["rmt"], grm), a)
-                return a
+                with jax.named_scope("ring"):
+                    a = rd(carry["act"], gact)
+                    if remat:
+                        a = jnp.where(rslot >= 0, rd(carry["rmt"], grm), a)
+                    return a
 
             def masked_dy(dy_pk, is_last):
-                dy = _unpack_payload(spec, dy_pk)
-                return jax.tree.map(
-                    lambda a: jnp.where(is_last, jnp.zeros_like(a), a), dy)
+                with jax.named_scope("wire"):
+                    dy = _unpack_payload(spec, dy_pk)
+                    return jax.tree.map(
+                        lambda a: jnp.where(is_last, jnp.zeros_like(a), a),
+                        dy)
 
             # ---- branches are PURE PRODUCERS: they read the carry's
             # ring buffers (conditional inputs alias freely) but every
@@ -1445,9 +1467,10 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
             # exception, ``lax.cond``-gated on the op class below —
             # see the comment at the gb/gs update. ----
             def zeros_gbd():
-                return [vary(jax.tree.map(
-                    lambda a: jnp.zeros(a.shape[1:], a.dtype), t))
-                    for t in zero_blocks_g]
+                with jax.named_scope("grad_accum"):
+                    return [vary(jax.tree.map(
+                        lambda a: jnp.zeros(a.shape[1:], a.dtype), t))
+                        for t in zero_blocks_g]
 
             def gs_of(gs_raw):
                 return jax.tree.map(lambda z, g: g.astype(z.dtype),
@@ -1476,10 +1499,11 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 blocks_c, flags_c = blocks_at()
                 tok, patch, frames, labels, mask = batch_inputs()
                 pin = rd(carry["fq"], jnp.maximum(src, 0))
-                out, ce = jcore(blocks_c, shared,
-                                _unpack_payload(spec, pin), tok, patch,
-                                frames, labels, mask, flags_c, is_first,
-                                is_last)
+                pay = _unpack_payload(spec, pin)
+                with jax.named_scope("fwd"):
+                    out, ce = jcore(blocks_c, shared, pay, tok, patch,
+                                    frames, labels, mask, flags_c,
+                                    is_first, is_last)
                 return ret(out=_pack_payload(spec, out), ce=ce,
                            nl=jnp.where(is_last, 1.0, 0.0), st_a=pin)
 
@@ -1494,39 +1518,44 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 blocks_c, flags_c = blocks_at()
                 tok, patch, frames, labels, mask = batch_inputs()
                 bnd = bnd_read(carry)
-                pay_in = jax.lax.cond(
-                    is_first,
-                    lambda _: jembed(shared, tok, patch, frames),
-                    lambda _: vary(_unpack_payload(spec, bnd)), None)
-                out, vjp = jax.vjp(
-                    lambda bp, pay: jchunk(bp, pay, flags_c),
-                    vary(blocks_c), vary(pay_in))
+                with jax.named_scope("replay"):
+                    pay_in = jax.lax.cond(
+                        is_first,
+                        lambda _: jembed(shared, tok, patch, frames),
+                        lambda _: vary(_unpack_payload(spec, bnd)), None)
+                    out, vjp = jax.vjp(
+                        lambda bp, pay: jchunk(bp, pay, flags_c),
+                        vary(blocks_c), vary(pay_in))
                 qdy = _unpack_payload(spec,
                                       rd(carry["bq"], jnp.maximum(src, 0)))
 
                 def head_pull(_):
-                    _, hvjp = jax.vjp(
-                        lambda po, sp: jhead(po, sp, labels, mask),
-                        vary(dict(out)), vary(shared))
+                    with jax.named_scope("replay"):
+                        _, hvjp = jax.vjp(
+                            lambda po, sp: jhead(po, sp, labels, mask),
+                            vary(dict(out)), vary(shared))
                     dy, gs = hvjp(to_varying(jnp.ones((), jnp.float32)))
                     return dy, gs_of(gs)
 
-                dy, gs = jax.lax.cond(
-                    is_last, head_pull,
-                    lambda _: (vary(dict(qdy)), zero_gs()), None)
-                gb_c, dx = vjp(dy)
+                with jax.named_scope("bwd"):
+                    dy, gs = jax.lax.cond(
+                        is_last, head_pull,
+                        lambda _: (vary(dict(qdy)), zero_gs()), None)
+                    gb_c, dx = vjp(dy)
 
                 def embed_pull(_):
-                    _, evjp = jax.vjp(
-                        lambda sp: jembed(sp, tok, patch, frames),
-                        vary(shared))
+                    with jax.named_scope("replay"):
+                        _, evjp = jax.vjp(
+                            lambda sp: jembed(sp, tok, patch, frames),
+                            vary(shared))
                     (gs_e,) = evjp(vary(dict(dx)))
                     return gs_of(gs_e)
 
-                gs = jax.tree.map(
-                    lambda a, b: a + b, gs,
-                    jax.lax.cond(is_first, embed_pull,
-                                 lambda _: zero_gs(), None))
+                with jax.named_scope("bwd"):
+                    gs = jax.tree.map(
+                        lambda a, b: a + b, gs,
+                        jax.lax.cond(is_first, embed_pull,
+                                     lambda _: zero_gs(), None))
                 return ret(out=_pack_payload(spec, dx), gbd=gb_c,
                            gsd=gs_of(gs))
 
@@ -1536,10 +1565,13 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 bnd = bnd_read(carry)
                 dy_pk = rd(carry["bq"], jnp.maximum(src, 0))
                 dy = _unpack_payload(spec, dy_pk)
-                _, vjp = jax.vjp(
-                    lambda pay: jchunk(vary(blocks_c), pay, flags_c),
-                    vary(_unpack_payload(spec, bnd)))
-                (dx,) = vjp(vary(dy))
+                pay = _unpack_payload(spec, bnd)
+                with jax.named_scope("replay"):
+                    _, vjp = jax.vjp(
+                        lambda pay: jchunk(vary(blocks_c), pay, flags_c),
+                        vary(pay))
+                with jax.named_scope("bwd"):
+                    (dx,) = vjp(vary(dy))
                 return ret(out=_pack_payload(spec, dx), st_a=bnd,
                            st_b=dy_pk)
 
@@ -1553,12 +1585,16 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 dy_pk = rd(carry["bq"], jnp.maximum(src, 0))
                 dy = masked_dy(dy_pk, is_last)
                 seed = jnp.where(is_last, 1.0, 0.0)
-                _, vjp = jax.vjp(
-                    lambda pay: jcore(vary(blocks_c), vary(shared), pay,
-                                      tok, patch, frames, labels, mask,
-                                      flags_c, is_first, is_last),
-                    vary(_unpack_payload(spec, bnd)))
-                (dx,) = vjp((vary(dy), to_varying(seed)))
+                pay = _unpack_payload(spec, bnd)
+                with jax.named_scope("replay"):
+                    _, vjp = jax.vjp(
+                        lambda pay: jcore(vary(blocks_c), vary(shared),
+                                          pay, tok, patch, frames, labels,
+                                          mask, flags_c, is_first,
+                                          is_last),
+                        vary(pay))
+                with jax.named_scope("bwd"):
+                    (dx,) = vjp((vary(dy), to_varying(seed)))
                 return ret(out=_pack_payload(spec, dx), st_a=bnd,
                            st_b=dy_pk)
 
@@ -1568,10 +1604,12 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 blocks_c, flags_c = blocks_at()
                 pay = _unpack_payload(spec, rd(carry["wx"], gw))
                 dy = _unpack_payload(spec, rd(carry["wdy"], gw))
-                _, vjp = jax.vjp(
-                    lambda bp: jchunk(bp, vary(pay), flags_c),
-                    vary(blocks_c))
-                (gb_c,) = vjp(vary(dy))
+                with jax.named_scope("replay"):
+                    _, vjp = jax.vjp(
+                        lambda bp: jchunk(bp, vary(pay), flags_c),
+                        vary(blocks_c))
+                with jax.named_scope("bwd"):
+                    (gb_c,) = vjp(vary(dy))
                 return ret(gbd=gb_c)
 
             def br_w_edge(_):            # split weight grad, first/last
@@ -1582,12 +1620,14 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 pay = _unpack_payload(spec, rd(carry["wx"], gw))
                 dy = masked_dy(rd(carry["wdy"], gw), is_last)
                 seed = jnp.where(is_last, 1.0, 0.0)
-                _, vjp = jax.vjp(
-                    lambda bp, sp: jcore(bp, sp, vary(pay), tok, patch,
-                                         frames, labels, mask, flags_c,
-                                         is_first, is_last),
-                    vary(blocks_c), vary(shared))
-                gb_c, gs = vjp((vary(dy), to_varying(seed)))
+                with jax.named_scope("replay"):
+                    _, vjp = jax.vjp(
+                        lambda bp, sp: jcore(bp, sp, vary(pay), tok, patch,
+                                             frames, labels, mask, flags_c,
+                                             is_first, is_last),
+                        vary(blocks_c), vary(shared))
+                with jax.named_scope("bwd"):
+                    gb_c, gs = vjp((vary(dy), to_varying(seed)))
                 return ret(gbd=gb_c, gsd=gs_of(gs))
 
             def br_rcp(_):               # hand act checkpoint -> remat
@@ -1639,23 +1679,25 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
             # zeros would pay the full accumulator memory traffic on
             # every one of them.
             is_g = (op >= BWD_MID) & (op <= WGT_LAST)
-            gb = jax.lax.cond(
-                is_g,
-                lambda t: [jax.tree.map(
-                    lambda g, d: jax.lax.dynamic_update_index_in_dim(
-                        g, jax.lax.dynamic_index_in_dim(g, c, 0, False)
-                        + d, c, 0), gt, dt)
-                    for gt, dt in zip(t, gb_d)],
-                lambda t: list(t), carry["gb"])
+            with jax.named_scope("grad_accum"):
+                gb = jax.lax.cond(
+                    is_g,
+                    lambda t: [jax.tree.map(
+                        lambda g, d: jax.lax.dynamic_update_index_in_dim(
+                            g, jax.lax.dynamic_index_in_dim(g, c, 0, False)
+                            + d, c, 0), gt, dt)
+                        for gt, dt in zip(t, gb_d)],
+                    lambda t: list(t), carry["gb"])
             is_gs = ((op == BWD_FIRST) | (op == BWD_LAST)
                      | (op == WGT_FIRST) | (op == WGT_LAST))
-            gs = jax.lax.cond(
-                is_gs,
-                lambda t: jax.tree.map(lambda a, b: a + b, t, gs_d),
-                lambda t: t, carry["gs"])
-            carry = dict(carry, gb=gb, gs=gs,
-                         loss=carry["loss"] + ce,
-                         nloss=carry["nloss"] + nl)
+            with jax.named_scope("grad_accum"):
+                gs = jax.lax.cond(
+                    is_gs,
+                    lambda t: jax.tree.map(lambda a, b: a + b, t, gs_d),
+                    lambda t: t, carry["gs"])
+                carry = dict(carry, gb=gb, gs=gs,
+                             loss=carry["loss"] + ce,
+                             nloss=carry["nloss"] + nl)
             return carry, out, row
 
         # ---- route: the shared wire protocol (:func:`_build_route`) —
@@ -1682,14 +1724,15 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                 # pure fixed cost.
                 if not xdev_have:
                     return fq, bq
-                anyx = jnp.any(functools.reduce(
-                    jnp.logical_or,
-                    [route_row_all[:, 5] == cd for cd in xdev_have]))
-                return jax.lax.cond(
-                    anyx,
-                    lambda a: route_x(a[0], a[1], a[2], route_row_all,
-                                      route_row_all[s_idx]),
-                    lambda a: (a[0], a[1]), (fq, bq, payload))
+                with jax.named_scope("wire"):
+                    anyx = jnp.any(functools.reduce(
+                        jnp.logical_or,
+                        [route_row_all[:, 5] == cd for cd in xdev_have]))
+                    return jax.lax.cond(
+                        anyx,
+                        lambda a: route_x(a[0], a[1], a[2], route_row_all,
+                                          route_row_all[s_idx]),
+                        lambda a: (a[0], a[1]), (fq, bq, payload))
 
             def repin(carry):
                 carry = dict(carry, act=pin_buf(carry["act"]))
@@ -1750,13 +1793,17 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
         if spec.grad_psum_bits:
             from repro.optim.compression import compressed_psum
             ef_local = jax.tree.map(lambda a: a[0], psum_ef)
-            gs, new_ef = compressed_psum(carry["gs"], pp, ef_local,
-                                         bits=spec.grad_psum_bits)
+            with jax.named_scope("grad_psum"):
+                gs, new_ef = compressed_psum(carry["gs"], pp, ef_local,
+                                             bits=spec.grad_psum_bits)
             new_ef = jax.tree.map(lambda a: a[None], new_ef)
         else:
-            gs = jax.tree.map(lambda a: jax.lax.psum(a, pp), carry["gs"])
-        loss = jax.lax.psum(carry["loss"], pp)
-        n = jax.lax.psum(carry["nloss"], pp)
+            with jax.named_scope("grad_psum"):
+                gs = jax.tree.map(lambda a: jax.lax.psum(a, pp),
+                                  carry["gs"])
+        with jax.named_scope("grad_psum"):
+            loss = jax.lax.psum(carry["loss"], pp)
+            n = jax.lax.psum(carry["nloss"], pp)
         metrics = {"loss": loss / jnp.maximum(n, 1.0), "n_microbatches": n}
         if ocfg is None:
             gb = [jax.tree.map(lambda a: a[None], t) for t in carry["gb"]]
@@ -1786,22 +1833,23 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
                                for b in t["blocks"]],
                     **{k: t[k] for k in t if k != "blocks"}}
 
-        g = jax.tree.map(lambda a: a.astype(jnp.float32) / opt_m,
-                         {"blocks": carry["gb"], **{k: gs[k] for k in gs}})
-        sq_b = sum(jnp.sum(jnp.square(a))
-                   for a in jax.tree.leaves(g["blocks"]))
-        sq_s = sum(jnp.sum(jnp.square(a)) for a in jax.tree.leaves(
-            {k: g[k] for k in g if k != "blocks"}))
-        gnorm = jnp.sqrt(jax.lax.psum(sq_b, pp) + sq_s + 1e-30)
-        opt_local = {"step": opt_state["step"],
-                     "mu": local_tree(opt_state["mu"]),
-                     "nu": local_tree(opt_state["nu"]),
-                     "master": local_tree(opt_state["master"])}
-        master, new_opt, omet = adamw_update(g, opt_local, ocfg,
-                                             use_kernel=True,
-                                             grad_norm=gnorm)
-        new_params = stack_tree(cast_like(
-            master, {"blocks": blocks, **shared}))
+        with jax.named_scope("optimizer"):
+            g = jax.tree.map(lambda a: a.astype(jnp.float32) / opt_m,
+                             {"blocks": carry["gb"], **{k: gs[k] for k in gs}})
+            sq_b = sum(jnp.sum(jnp.square(a))
+                       for a in jax.tree.leaves(g["blocks"]))
+            sq_s = sum(jnp.sum(jnp.square(a)) for a in jax.tree.leaves(
+                {k: g[k] for k in g if k != "blocks"}))
+            gnorm = jnp.sqrt(jax.lax.psum(sq_b, pp) + sq_s + 1e-30)
+            opt_local = {"step": opt_state["step"],
+                         "mu": local_tree(opt_state["mu"]),
+                         "nu": local_tree(opt_state["nu"]),
+                         "master": local_tree(opt_state["master"])}
+            master, new_opt, omet = adamw_update(g, opt_local, ocfg,
+                                                 use_kernel=True,
+                                                 grad_norm=gnorm)
+            new_params = stack_tree(cast_like(
+                master, {"blocks": blocks, **shared}))
         new_opt = {"step": new_opt["step"],
                    "mu": stack_tree(new_opt["mu"]),
                    "nu": stack_tree(new_opt["nu"]),
@@ -1892,4 +1940,5 @@ def _ppermute(x, axis, perm):
     collective entirely and pass the payload through."""
     if all(s == d for s, d in perm):
         return x
-    return jax.tree.map(lambda a: jax.lax.ppermute(a, axis, perm), x)
+    with jax.named_scope("exchange"):
+        return jax.tree.map(lambda a: jax.lax.ppermute(a, axis, perm), x)

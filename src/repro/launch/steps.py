@@ -501,12 +501,14 @@ def make_pipeline_train_step(cfg: ModelConfig, shape: ShapeConfig,
                 grads, metrics, new_ef = grads_fn(params, batch, psum_ef)
             else:
                 grads, metrics = grads_fn(params, batch)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32) / m,
-                                 grads)
+            with jax.named_scope("optimizer"):
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) / m, grads)
             if not offload:
-                master, opt_state, om = adamw_update(grads, opt_state,
-                                                     ocfg)
-                params = cast_like(master, params)
+                with jax.named_scope("optimizer"):
+                    master, opt_state, om = adamw_update(grads, opt_state,
+                                                         ocfg)
+                    params = cast_like(master, params)
                 out = (params, opt_state, {**metrics, **om})
                 return out + ((new_ef,) if psum_bits else ())
             # Chronos-Offload: device AdamW updates shallow chunks +
@@ -519,9 +521,10 @@ def make_pipeline_train_step(cfg: ModelConfig, shape: ShapeConfig,
             master, opt_state, om = adamw_update(g_dev, opt_state, ocfg)
             p_shallow, p_deep = split_deep_shallow(params["blocks"], vch,
                                                    n_off)
-            new_shallow = cast_like(master["blocks"], p_shallow)
-            shared_new = {k: cast_like(master[k], params[k])
-                          for k in master if k != "blocks"}
+            with jax.named_scope("optimizer"):
+                new_shallow = cast_like(master["blocks"], p_shallow)
+                shared_new = {k: cast_like(master[k], params[k])
+                              for k in master if k != "blocks"}
             params = {"blocks": merge_deep_shallow(new_shallow, p_deep),
                       **shared_new}
             out = (params, opt_state, {**metrics, **om},

@@ -269,20 +269,23 @@ def train_pipeline(tc: TrainConfig, *, mesh,
         tree = {"params": params_, "opt": opt_}
         extra = {"step": next_step_, "data": pipe.state(),
                  "layout": layout_meta}
-        try:
-            (ck.save if sync else ck.save_async)(save_step, tree,
-                                                 extra=extra)
-        except Exception as e:               # noqa: BLE001
-            log(f"[train-pp] checkpoint write died ({e!r}) -> "
-                "synchronous retry")
-            ck.save(save_step, tree, extra=extra)
+        with jax.profiler.TraceAnnotation("checkpoint"):
+            try:
+                (ck.save if sync else ck.save_async)(save_step, tree,
+                                                     extra=extra)
+            except Exception as e:               # noqa: BLE001
+                log(f"[train-pp] checkpoint write died ({e!r}) -> "
+                    "synchronous retry")
+                ck.save(save_step, tree, extra=extra)
 
     def fold_pending(params_):
-        new_deep = runner.collect()           # bf16 upload (warm-up win)
-        shallow, _ = split_deep_shallow(params_["blocks"], v, n_off)
-        return jax.device_put(
-            {**params_, "blocks": merge_deep_shallow(shallow, new_deep)},
-            in_sh[0])
+        with jax.profiler.TraceAnnotation("offload_collect"):
+            new_deep = runner.collect()       # bf16 upload (warm-up win)
+            shallow, _ = split_deep_shallow(params_["blocks"], v, n_off)
+            return jax.device_put(
+                {**params_,
+                 "blocks": merge_deep_shallow(shallow, new_deep)},
+                in_sh[0])
 
     if latest is None:
         # durable step-0 snapshot: a failure before the first periodic
@@ -311,44 +314,49 @@ def train_pipeline(tc: TrainConfig, *, mesh,
                 break
             if injector is not None:
                 injector.on_step_start(step)
-            t0 = time.time()
-            batch = {k: jnp.asarray(b) for k, b in pipe.next().items()}
-            if pending:
-                t_c = time.time()
-                params, pending = fold_pending(params), False
-                collect_wait_s += time.time() - t_c
-            if watchdog is not None:
-                watchdog.arm()
-            out = jit_step(params, opt_state, batch, psum_ef) \
-                if psum_bits else jit_step(params, opt_state, batch)
-            if psum_bits:
-                *out, psum_ef = out
-            if offload:
-                params, opt_state, metrics, deep_grads = out
+            with jax.profiler.StepTraceAnnotation("train",
+                                                  step_num=step):
+                t0 = time.time()
+                with jax.profiler.TraceAnnotation("input"):
+                    batch = {k: jnp.asarray(b)
+                             for k, b in pipe.next().items()}
+                if pending:
+                    t_c = time.time()
+                    params, pending = fold_pending(params), False
+                    collect_wait_s += time.time() - t_c
+                if watchdog is not None:
+                    watchdog.arm()
+                out = jit_step(params, opt_state, batch, psum_ef) \
+                    if psum_bits else jit_step(params, opt_state, batch)
                 if psum_bits:
-                    # host shipment arrives quantized; the host AdamW
-                    # wants fp32
-                    from repro.optim.compression import dequantize_int8
-                    deep_grads = jax.tree.map(
-                        lambda t: dequantize_int8(*t), deep_grads,
-                        is_leaf=lambda x: isinstance(x, tuple))
-                runner.submit(deep_grads)     # grads down + host AdamW
-                pending = True
-            else:
-                params, opt_state, metrics = out
-            loss = float(metrics["loss"])     # blocks until step done
-            if injector is not None:
-                injector.on_step_end(step, watchdog)
-            if watchdog is not None:
-                if watchdog.check():
-                    raise DeviceLossError(-1, "hung_collective", step)
-                watchdog.disarm()
-            losses.append(loss)
-            loss_by_step[step] = loss
-            next_step = step + 1
-            if first_step_s is None:
-                first_step_s = time.time() - t_start
-            dt = time.time() - t0
+                    *out, psum_ef = out
+                if offload:
+                    params, opt_state, metrics, deep_grads = out
+                    if psum_bits:
+                        # host shipment arrives quantized; the host AdamW
+                        # wants fp32
+                        from repro.optim.compression import dequantize_int8
+                        deep_grads = jax.tree.map(
+                            lambda t: dequantize_int8(*t), deep_grads,
+                            is_leaf=lambda x: isinstance(x, tuple))
+                    with jax.profiler.TraceAnnotation("offload_submit"):
+                        runner.submit(deep_grads)  # grads down + host AdamW
+                    pending = True
+                else:
+                    params, opt_state, metrics = out
+                loss = float(metrics["loss"])     # blocks until step done
+                if injector is not None:
+                    injector.on_step_end(step, watchdog)
+                if watchdog is not None:
+                    if watchdog.check():
+                        raise DeviceLossError(-1, "hung_collective", step)
+                    watchdog.disarm()
+                losses.append(loss)
+                loss_by_step[step] = loss
+                next_step = step + 1
+                if first_step_s is None:
+                    first_step_s = time.time() - t_start
+                dt = time.time() - t0
             if injector is not None:
                 dt = injector.step_time(step, dt)
             action = monitor.record_step(dt)

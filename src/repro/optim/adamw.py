@@ -57,44 +57,45 @@ def adamw_update(grads, state, cfg: OptimizerConfig, *,
     ``shard_map`` region (the in-executor fused optimizer) pass the
     psum-reduced norm because ``global_norm`` over the local tree would
     miss the other pipeline stages' block gradients."""
-    if update_fn is None and use_kernel:
-        from repro.kernels.fused_adamw.ops import adamw_update_leaf
-        update_fn = adamw_update_leaf
-    step = state["step"] + 1
-    lr = lr_at(cfg, step)
-    gnorm = global_norm(grads) if grad_norm is None else grad_norm
-    clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9)) \
-        if cfg.grad_clip > 0 else jnp.asarray(1.0)
-    b1, b2, eps = cfg.beta1, cfg.beta2, cfg.eps
-    bc1 = 1 - b1 ** step.astype(jnp.float32)
-    bc2 = 1 - b2 ** step.astype(jnp.float32)
-    masks = _decay_masks(grads)
+    with jax.named_scope("optimizer"):
+        if update_fn is None and use_kernel:
+            from repro.kernels.fused_adamw.ops import adamw_update_leaf
+            update_fn = adamw_update_leaf
+        step = state["step"] + 1
+        lr = lr_at(cfg, step)
+        gnorm = global_norm(grads) if grad_norm is None else grad_norm
+        clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-9)) \
+            if cfg.grad_clip > 0 else jnp.asarray(1.0)
+        b1, b2, eps = cfg.beta1, cfg.beta2, cfg.eps
+        bc1 = 1 - b1 ** step.astype(jnp.float32)
+        bc2 = 1 - b2 ** step.astype(jnp.float32)
+        masks = _decay_masks(grads)
 
-    def upd(g, mu, nu, w, decay_on):
-        g = g.astype(jnp.float32) * clip
-        if update_fn is not None:
-            return update_fn(g, mu, nu, w, lr=lr, b1=b1, b2=b2, eps=eps,
-                             bc1=bc1, bc2=bc2,
-                             wd=cfg.weight_decay if decay_on else 0.0)
-        mu = b1 * mu + (1 - b1) * g
-        nu = b2 * nu + (1 - b2) * jnp.square(g)
-        upd = (mu / bc1) / (jnp.sqrt(nu / bc2) + eps)
-        if decay_on:
-            upd = upd + cfg.weight_decay * w
-        w = w - lr * upd
-        return mu, nu, w
+        def upd(g, mu, nu, w, decay_on):
+            g = g.astype(jnp.float32) * clip
+            if update_fn is not None:
+                return update_fn(g, mu, nu, w, lr=lr, b1=b1, b2=b2, eps=eps,
+                                 bc1=bc1, bc2=bc2,
+                                 wd=cfg.weight_decay if decay_on else 0.0)
+            mu = b1 * mu + (1 - b1) * g
+            nu = b2 * nu + (1 - b2) * jnp.square(g)
+            upd = (mu / bc1) / (jnp.sqrt(nu / bc2) + eps)
+            if decay_on:
+                upd = upd + cfg.weight_decay * w
+            w = w - lr * upd
+            return mu, nu, w
 
-    out = jax.tree.map(upd, grads, state["mu"], state["nu"],
-                       state["master"], masks)
-    mu = jax.tree.map(lambda o: o[0], out, is_leaf=lambda x:
-                      isinstance(x, tuple))
-    nu = jax.tree.map(lambda o: o[1], out, is_leaf=lambda x:
-                      isinstance(x, tuple))
-    master = jax.tree.map(lambda o: o[2], out, is_leaf=lambda x:
+        out = jax.tree.map(upd, grads, state["mu"], state["nu"],
+                           state["master"], masks)
+        mu = jax.tree.map(lambda o: o[0], out, is_leaf=lambda x:
                           isinstance(x, tuple))
-    new_state = {"step": step, "mu": mu, "nu": nu, "master": master}
-    metrics = {"grad_norm": gnorm, "lr": lr}
-    return master, new_state, metrics
+        nu = jax.tree.map(lambda o: o[1], out, is_leaf=lambda x:
+                          isinstance(x, tuple))
+        master = jax.tree.map(lambda o: o[2], out, is_leaf=lambda x:
+                              isinstance(x, tuple))
+        new_state = {"step": step, "mu": mu, "nu": nu, "master": master}
+        metrics = {"grad_norm": gnorm, "lr": lr}
+        return master, new_state, metrics
 
 
 def cast_like(tree_fp32, params_proto):

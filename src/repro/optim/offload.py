@@ -104,7 +104,8 @@ class ChronosOffloadRunner:
 
         def work():
             try:
-                self._result = self.opt.update(grads_host, clip_coef)
+                with jax.profiler.TraceAnnotation("host_update"):
+                    self._result = self.opt.update(grads_host, clip_coef)
             except BaseException as e:                        # noqa: BLE001
                 self._error = e
 
