@@ -62,10 +62,11 @@ from repro.core.placement import Placement
 from repro.core.schedules import get_schedule
 from repro.core.tasktable import (B_OPS, BWD_FIRST, BWD_LAST, BWD_MID,
                                   F_OPS, FWD_FIRST, FWD_LAST, FWD_MID,
-                                  IDLE, R_OPS, RCP_MID, SEND_B_DOWN,
-                                  SEND_B_LOC, SEND_BWD, SEND_F_LOC,
-                                  SEND_F_UP, SEND_FWD, SEND_HOPB,
-                                  SEND_HOPF, SEND_NONE, TaskTable,
+                                  IDLE, LOCAL_SENDS, R_OPS, RCP_MID,
+                                  SEND_B_DOWN, SEND_B_LOC, SEND_BWD,
+                                  SEND_F_LOC, SEND_F_UP, SEND_FWD,
+                                  SEND_HOPB, SEND_HOPF, SEND_NONE,
+                                  TaskTable,
                                   W_OPS, WGT_FIRST,
                                   WGT_LAST, WGT_MID, build_task_table,
                                   factor_phases, replay_phases)
@@ -80,11 +81,6 @@ from repro.models.transformer import _init_layer
 #: (the pre-phase per-tick interpreter, kept for A/B benchmarking —
 #: ``benchmarks/pipeline_exec.py`` measures both).
 EXECUTOR_ENV = "REPRO_PIPELINE_EXECUTOR"
-
-#: default for :func:`make_pipeline_spec`'s ``overlap`` flag (the
-#: double-buffered cross-device exchange).  "0"/"false" restores the
-#: synchronous in-tick wire everywhere, e.g. for A/B benchmarking.
-OVERLAP_ENV = "REPRO_PIPELINE_OVERLAP"
 
 #: wire-protocol switch point (bytes of all-gathered payload per tick):
 #: at or below this, the phase executors use the single-collective
@@ -195,8 +191,7 @@ def _build_route(tab: "TaskTable", P_: int, pp: str, snds, use_ag: bool,
     # no cross-device send code in the whole table (P=1, or an entirely
     # device-local placement): the collective route short-circuits away,
     # deferred or not — mirroring _ppermute's identity-perm skip
-    has_xdev = bool(frozenset(snds) - frozenset(
-        (SEND_NONE, SEND_F_LOC, SEND_B_LOC)))
+    has_xdev = bool(frozenset(snds) - frozenset(LOCAL_SENDS))
 
     def route_xdev(fq, bq, out, row_all, row):
         if not has_xdev:
@@ -440,7 +435,7 @@ def make_pipeline_spec(cfg: ModelConfig, *, P: int, v: int, m: int,
                        microbatch: int, seq_len: int, schedule: str,
                        pp_axis: str = "pp", n_seq: int = 1,
                        kernels: str = "xla", wire: str = "fp32",
-                       overlap: Optional[bool] = None,
+                       overlap: bool = False,
                        grad_psum_bits: Optional[int] = None,
                        **sched_kw) -> PipelineSpec:
     seq_schedules = ("seq1f1b", "chronos_seq")
@@ -463,12 +458,10 @@ def make_pipeline_spec(cfg: ModelConfig, *, P: int, v: int, m: int,
     # (interleaved striping unless the generator carries one, e.g. the
     # V-shape family's fold-back)
     layout = StageLayout.build(cfg, P, v, placement=sched.placement)
-    # double-buffered (overlapped) exchange is the default; the env var
-    # (or overlap=False) restores the synchronous in-tick wire for A/B
-    # measurement — both build the same per-device op order, so gradient
-    # equivalence holds bitwise across the pair.
-    if overlap is None:
-        overlap = os.environ.get(OVERLAP_ENV, "1") not in ("0", "false")
+    # the wire's delivery contract: synchronous (each tick's exchange
+    # delivers this tick's payload) unless ``overlap=True`` asks for the
+    # double-buffered table — both keep the per-device op order, so
+    # gradients are bitwise equal across the pair
     assert wire in ("fp32", "bf16", "int8"), f"unknown wire {wire!r}"
     table = build_task_table(sched, overlap=overlap)
     prefix = cfg.vision.num_patches if cfg.vision is not None else 0
@@ -1712,8 +1705,7 @@ def _make_train_grads_phase(spec: PipelineSpec, mesh, ocfg=None,
             route_x, route_l = _build_route(tab, P_, pp, snds, use_ag,
                                             s_idx)
             defer = tab.overlap and route_x.has_xdev
-            xdev_have = [cd for cd in snds
-                         if cd not in (SEND_NONE, SEND_F_LOC, SEND_B_LOC)]
+            xdev_have = [cd for cd in snds if cd not in LOCAL_SENDS]
 
             def skip_quiet(route_row_all, fq, bq, payload):
                 # Quiet ticks (no device holds a cross-device send code —

@@ -88,6 +88,9 @@ from repro.core.schedule import B, F, R, Schedule, W, _dep_keys
 (SEND_NONE, SEND_FWD, SEND_HOPF, SEND_BWD, SEND_HOPB,
  SEND_F_UP, SEND_B_DOWN, SEND_F_LOC, SEND_B_LOC) = range(9)
 
+#: send codes that never leave their device
+LOCAL_SENDS = (SEND_NONE, SEND_F_LOC, SEND_B_LOC)
+
 RECV_CHANNELS = ("dn", "up", "loc")
 
 

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import logging
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -21,6 +22,8 @@ from repro.models import LM
 from repro.models.sharding import ShardEnv, sanitize_spec, shard_env
 from repro.optim import adamw_init, adamw_update, cast_like, zero_state_specs
 from repro.optim.adamw import drop_fsdp
+
+_log = logging.getLogger(__name__)
 
 
 # ---------------------------------------------------------------------------
@@ -385,6 +388,10 @@ def make_pipeline_train_step(cfg: ModelConfig, shape: ShapeConfig,
         seq_len=shape.seq_len, schedule=plan.schedule, pp_axis=pp_axis,
         n_seq=plan.seq_chunks, kernels=plan.kernels, wire=plan.wire,
         grad_psum_bits=psum_bits, **plan_schedule_kwargs(plan))
+    _log.info("pipeline %s P=%d v=%d m=%d: %s wire, %d ticks",
+              plan.schedule, P_, plan.num_chunks, m,
+              "double-buffered" if spec.table.overlap else "synchronous",
+              spec.table.T)
     if extras is not None:
         extras["spec"] = spec
     offload = plan.offload.enabled and plan.offload.num_offload_chunks > 0

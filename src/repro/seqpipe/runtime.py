@@ -50,9 +50,8 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core.pipeline_runtime import PipelineSpec, _embed_tokens
-from repro.core.tasktable import (SEND_B_LOC, SEND_BWD, SEND_F_LOC,
-                                  SEND_FWD, SEND_HOPB, SEND_HOPF,
-                                  SEND_NONE)
+from repro.core.tasktable import (LOCAL_SENDS, SEND_BWD, SEND_FWD,
+                                  SEND_HOPB, SEND_HOPF)
 from repro.kernels import check_vma
 from repro.models import backend as compute_backend
 from repro.models import layers as L
@@ -812,8 +811,7 @@ def _make_seq_train_grads_phase(spec: PipelineSpec, mesh):
             route_x, route_l = _build_route(tab, P_, pp, snds, use_ag,
                                             s_idx)
             defer = tab.overlap and route_x.has_xdev
-            xdev_have = [cd for cd in snds
-                         if cd not in (SEND_NONE, SEND_F_LOC, SEND_B_LOC)]
+            xdev_have = [cd for cd in snds if cd not in LOCAL_SENDS]
 
             def skip_quiet(route_row_all, fq, bq, payload):
                 # quiet ticks skip the collective rendezvous — the row

@@ -48,6 +48,16 @@ errors on the reduced config (bf16 ~5.6e-3, int8 ~4.1e-2):
     wire_bf16   bf16 payloads   normalized tolerance 2e-2
     wire_int8   int8 + per-tile scale in the aux words   tolerance 1e-1
 
+Tick-contract pair (same schedule, chronos v=2; side a takes
+``make_pipeline_spec``'s default synchronous table, side b pins the
+double-buffered one).  Both tables keep the per-device op order, so the
+tolerance is 0.0 — bitwise:
+    tick_contract   overlap=False (default) vs overlap=True
+
+``--overlap`` runs any other pair but ``opt`` with the double-buffered
+table on both sides (``overlap=True``), so the deferred exchange, its
+queues and the ``wire`` carry are checked under the same tolerances.
+
 Optimizer-fusion pair:
     opt     zb_h1 with kernels="fused": N steps of the in-executor
             fused AdamW (make_train_update_fn — update inside the
@@ -59,10 +69,11 @@ Optimizer-fusion pair:
             square-sums), so the trajectory matches to float-summation
             tolerance 1e-5.
 
-Usage: python split_fused_check.py [--pair NAME] [P] [m]
+Usage: python split_fused_check.py [--pair NAME] [--overlap] [P] [m]
 Exits 0 when max |g_a - g_b| <= tol; prints MAXERR=... for the parent
 test to parse.
 """
+import functools
 import os
 import sys
 
@@ -71,6 +82,9 @@ pair = "zb"
 if args and args[0] == "--pair":
     pair = args[1]
     args = args[2:]
+pinned = bool(args) and args[0] == "--overlap"
+if pinned:
+    args = args[1:]
 P_ = int(args[0]) if len(args) > 0 else 2
 m = int(args[1]) if len(args) > 1 else 4
 os.environ["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={P_}"
@@ -87,6 +101,9 @@ from repro.models import shard_env  # noqa: E402
 
 mbB, S = 2, 17
 mesh = make_mesh((P_,), ("pp",))
+if pinned:
+    assert pair not in ("opt", "tick_contract"), pair
+    make_pipeline_spec = functools.partial(make_pipeline_spec, overlap=True)
 
 WIRE_PAIRS = {"wire_bf16": ("bf16", 2e-2), "wire_int8": ("int8", 1e-1)}
 
@@ -157,6 +174,14 @@ elif pair == "recomp":
                                 rho=1.0, recomp_chunks=1)
     assert spec_b.table.has_r and not spec_a.table.has_r
     tol = 0.0
+elif pair == "tick_contract":
+    spec_a = make_pipeline_spec(cfg, P=P_, v=2, m=m, microbatch=mbB,
+                                seq_len=S, schedule="chronos")
+    spec_b = make_pipeline_spec(cfg, P=P_, v=2, m=m, microbatch=mbB,
+                                seq_len=S, schedule="chronos", overlap=True)
+    assert not spec_a.table.overlap and spec_b.table.overlap
+    assert spec_a.table.T < spec_b.table.T
+    tol = 0.0
 elif pair == "seq":
     spec_a = make_pipeline_spec(cfg, P=P_, v=2, m=m, microbatch=mbB,
                                 seq_len=S, schedule="chronos")
@@ -192,6 +217,8 @@ elif pair in FUSED_PAIRS:
     tol = 1e-4
 else:
     raise SystemExit(f"unknown pair {pair!r}")
+if pinned:
+    assert spec_a.table.overlap and spec_b.table.overlap
 
 params, _ = init_pipeline_params(jax.random.key(0), cfg, spec_a.layout)
 params_b = params

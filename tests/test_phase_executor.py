@@ -155,6 +155,45 @@ def test_deferred_exchange_short_circuits_without_xdev(schedule, n_seq):
         grads["sync"], grads["overlap"])
 
 
+@pytest.mark.parametrize("schedule,P,kw,overlap,want_T", [
+    ("chronos", 4, {}, None, 44),
+    ("interleaved", 4, {}, None, 38),
+    ("v_min", 4, {}, None, 59),
+    ("chronos_seq", 4, {"n_seq": 2}, None, 76),
+    ("chronos_recomp", 1, {"recomp_chunks": 1}, None, 40),
+    ("chronos", 4, {}, True, 78),
+    ("interleaved", 4, {}, True, 52),
+    ("interleaved", 4, {}, False, 38),
+])
+def test_tick_contract_default_is_synchronous(schedule, P, kw, overlap,
+                                              want_T):
+    """``make_pipeline_spec`` builds the synchronous task table unless
+    ``overlap=True`` pins the double-buffered one.  Without a
+    cross-device send (P=1) both contracts give the same table, so the
+    one-chip step keeps its ticks and ring depths.  Tables only, no
+    compile."""
+    from repro.configs import get_reduced
+    from repro.core.pipeline_runtime import make_pipeline_spec
+
+    def table(**ov):
+        return make_pipeline_spec(get_reduced("tinyllama-1.1b"), P=P, v=2,
+                                  m=8, microbatch=2, seq_len=17,
+                                  schedule=schedule, **kw, **ov).table
+
+    tab = table(**({} if overlap is None else {"overlap": overlap}))
+    assert tab.overlap is bool(overlap) and tab.T == want_T
+    other = table(overlap=not overlap)
+    if P == 1:
+        np.testing.assert_array_equal(tab.arrays(), other.arrays())
+        assert (tab.fq_depth, tab.bq_depth, tab.act_depth,
+                tab.rmt_depth) == (other.fq_depth, other.bq_depth,
+                                   other.act_depth, other.rmt_depth)
+    else:
+        # the double-buffered contract stretches every cross-device
+        # dependency, so it always takes more ticks
+        assert (tab.T < other.T) is not bool(overlap)
+
+
 def test_phase_executor_bf16_recomp_p1_grads_finite():
     """The one-chip shape of ``chip_smoke.py`` at toy widths: bf16
     parameters, ``chronos_recomp`` P=1 v=2.  The head and embed

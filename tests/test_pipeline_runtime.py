@@ -86,6 +86,31 @@ def test_recomp_matches_norecomp_runtime_bitwise():
     assert "MAXERR=0.000e+00" in r.stdout
 
 
+def test_sync_default_matches_overlap_runtime_bitwise():
+    """The default synchronous table (chronos P=2 m=4, 20 ticks) must
+    reproduce the double-buffered table's gradients *bitwise*: both keep
+    the per-device op order."""
+    r = _run([sys.executable, SPLIT_HELPER, "--pair", "tick_contract",
+              "2", "4"])
+    assert r.returncode == 0, \
+        f"tick-contract pair failed:\n{r.stdout[-2000:]}\n{r.stderr[-3000:]}"
+    assert "MAXERR=0.000e+00" in r.stdout
+
+
+@pytest.mark.parametrize("pair", ["zb", "recomp", "seq", "wire_int8"])
+def test_pairs_on_double_buffered_wire(pair):
+    """The same pairs with ``overlap=True`` on both sides: the deferred
+    exchange, its queues and the ``wire`` carry under the W ops (zb),
+    the R ops (recomp), the seq-chunked executor and the int8 wire,
+    at the pairs' own tolerances."""
+    r = _run([sys.executable, SPLIT_HELPER, "--pair", pair, "--overlap",
+              "2", "4"])
+    assert r.returncode == 0, \
+        f"{pair} (overlap) failed:\n{r.stdout[-2000:]}\n{r.stderr[-3000:]}"
+    assert ("MAXERR=0.000e+00" if pair == "recomp" else "MAXERR=") \
+        in r.stdout
+
+
 def test_offload_pipeline_step_shapes():
     """Chronos-Offload step builder (trace only): device opt state
     excludes the deep chunks; the step returns their gradients."""
