@@ -2,100 +2,80 @@
 of each layer and how the seed makes them.
 
 Read from ``bench/configs/<name>.json`` (the keys of the model's public
-config).  The reference, the weights and the FLOP counts use this and
-nothing of the program; ``program.py`` alone maps it onto the program.
+config), whose ``arch`` key names the module in ``bench/arch/`` that
+describes it.  The reference, the weights and the FLOP counts use this
+and nothing of the program; ``program.py`` alone maps it onto the
+program.
 """
 from __future__ import annotations
 
+import importlib
 import json
 import math
 import os
-from dataclasses import dataclass
+from types import ModuleType
 from typing import Dict, List, Tuple
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-
-
-@dataclass(frozen=True)
-class Model:
-    name: str
-    d: int
-    layers: int
-    vocab: int              # ids drawn by the traffic
-    vocab_rows: int         # rows of the embedding (vocab padded)
-    tie: bool
-    eps: float
-    param_dtype: str
-    heads: int
-    kv_heads: int
-    hd: int
-    ff: int
-    rope_theta: float
-
-
-def load_config(name: str) -> dict:
-    with open(os.path.join(HERE, "configs", f"{name}.json")) as f:
-        return json.load(f)
-
-
-def from_config(c: dict) -> Model:
-    if c["hidden_act"] != "silu":
-        raise ValueError(f"{c['name']}: only SwiGLU MLPs are described here")
-    return Model(
-        name=c["name"], d=c["hidden_size"],
-        layers=c["num_hidden_layers"], vocab=c["vocab_size"],
-        vocab_rows=c["vocab_size"], tie=c["tie_word_embeddings"],
-        eps=c["rms_norm_eps"], param_dtype=c["dtype"]["params"],
-        heads=c["num_attention_heads"], kv_heads=c["num_key_value_heads"],
-        hd=c["hidden_size"] // c["num_attention_heads"],
-        ff=c["intermediate_size"], rope_theta=c["rope_theta"])
-
 
 # A leaf: (dotted path, shape, init, dtype).  init is ("normal", std) or
 # ("ones",).
 Leaf = Tuple[str, Tuple[int, ...], tuple, str]
 
 
-def layer_leaves(m: Model) -> List[Leaf]:
-    """The leaves of one layer, in a fixed order (the order keys the
-    seed).  Names are those of the program's parameter tree."""
-    p, d = m.param_dtype, m.d
-    qd, kvd = m.heads * m.hd, m.kv_heads * m.hd
-    return [
-        ("norm1.scale", (d,), ("ones",), p),
-        ("attn.wq", (d, qd), ("normal", d ** -0.5), p),
-        ("attn.wk", (d, kvd), ("normal", d ** -0.5), p),
-        ("attn.wv", (d, kvd), ("normal", d ** -0.5), p),
-        ("attn.wo", (qd, d), ("normal", qd ** -0.5), p),
-        ("norm2.scale", (d,), ("ones",), p),
-        ("mlp.wi", (d, m.ff), ("normal", d ** -0.5), p),
-        ("mlp.wg", (d, m.ff), ("normal", d ** -0.5), p),
-        ("mlp.wo", (m.ff, d), ("normal", m.ff ** -0.5), p),
-    ]
+def load_config(name: str) -> dict:
+    path = os.path.join(HERE, "configs", f"{name}.json")
+    with open(path) as f:
+        c = json.load(f)
+    _module(c, path)
+    return c
 
 
-def shared_leaves(m: Model) -> List[Leaf]:
-    p, d, v = m.param_dtype, m.d, m.vocab_rows
-    out = [("embed.tokens", (v, d), ("normal", d ** -0.5), p)]
-    if not m.tie:
-        out.append(("embed.head", (d, v), ("normal", d ** -0.5), p))
-    out.append(("final_norm.scale", (d,), ("ones",), p))
-    return out
+def _module(c: dict, where: str) -> ModuleType:
+    """The module ``bench/arch/<arch>.py`` that the configuration's
+    ``arch`` key names; an error that names ``where`` if there is none."""
+    arch = c.get("arch")
+    if not isinstance(arch, str) or not arch.isidentifier():
+        raise ValueError(f"{where}: 'arch' must name a module in bench/arch/"
+                         f", not {arch!r}")
+    try:
+        return importlib.import_module(f"bench.arch.{arch}")
+    except ModuleNotFoundError as e:
+        if e.name != f"bench.arch.{arch}":
+            raise
+        raise ValueError(f"{where}: unknown arch {arch!r}: there is no "
+                         f"bench/arch/{arch}.py") from e
 
 
-def decays(path: str, shape) -> bool:
-    """AdamW's decay rule as the traffic states it: matmul, embedding and
-    head weights (rank >= 2 in one layer), not norm scales."""
-    return len(shape) >= 2
+def from_config(c: dict):
+    """The model, as the configuration's architecture module describes
+    it."""
+    return _module(c, c.get("name", "the configuration")).describe(c)
 
 
-def matmul_params(m: Model) -> Dict[str, int]:
-    """Parameters that enter a matmul per token: per layer, and the head
-    (the embedding lookup is a gather, not a matmul)."""
-    per_layer = sum(math.prod(s) for _, s, _, _ in layer_leaves(m)
-                    if len(s) == 2)
-    return {"layer": per_layer, "head": m.d * m.vocab_rows}
+def arch(m) -> ModuleType:
+    """The architecture module that described ``m``."""
+    return importlib.import_module(f"bench.arch.{m.arch}")
 
 
-def layer_params(m: Model) -> int:
-    return sum(math.prod(s) for _, s, _, _ in layer_leaves(m))
+def layer_leaves(m, g: int) -> List[Leaf]:
+    """The leaves of global layer ``g``, in a fixed order (the order keys
+    the seed).  Names are those of the program's parameter tree."""
+    return arch(m).layer_leaves(m, g)
+
+
+def shared_leaves(m) -> List[Leaf]:
+    return arch(m).shared_leaves(m)
+
+
+def decays(m, path: str, shape) -> bool:
+    """AdamW's decay rule of ``m``'s architecture."""
+    return arch(m).decays(path, shape)
+
+
+def matmul_params(m) -> Dict[str, int]:
+    return arch(m).matmul_params(m)
+
+
+def layer_params(m, g: int = 0) -> int:
+    return sum(math.prod(s) for _, s, _, _ in layer_leaves(m, g))

@@ -20,17 +20,6 @@ from bench import model as M
 from bench import weights as W
 
 
-def program_config(m: M.Model):
-    from repro.configs.base import ModelConfig
-    return ModelConfig(name=m.name, family="dense", num_layers=m.layers,
-                       d_model=m.d, num_heads=m.heads,
-                       num_kv_heads=m.kv_heads, d_ff=m.ff, head_dim=m.hd,
-                       rope_theta=m.rope_theta, vocab_size=m.vocab_rows,
-                       tie_embeddings=m.tie, norm_eps=m.eps, act="silu",
-                       param_dtype=m.param_dtype,
-                       compute_dtype=m.param_dtype)
-
-
 def optimizer_config(traffic: dict):
     from repro.configs.base import OptimizerConfig
     return OptimizerConfig(**traffic["optimizer"])
@@ -51,7 +40,7 @@ def _same(path, shape, dtype, want_shape, want_dtype):
 @dataclasses.dataclass
 class Program:
     """One compiled training step with its shardings and layout."""
-    model: M.Model
+    model: object                # described by bench/arch/<arch>.py
     traffic: dict
     devices: list
     step: object = None          # (params, opt, batch) -> ...
@@ -80,7 +69,7 @@ class Program:
                             t["microbatches"] * t["microbatch_size"], "train")
         extras = {}
         fn, self.structs, self.in_sh, out_sh = make_pipeline_train_step(
-            program_config(self.model), shape, plan,
+            M.arch(self.model).program_config(self.model), shape, plan,
             optimizer_config(t), mesh, rules, extras=extras)
         self.spec = extras["spec"]
         if self.spec.layout.L_pad != self.model.layers:
@@ -95,22 +84,23 @@ class Program:
 
     # -- layout ---------------------------------------------------------
     def block_layers(self) -> np.ndarray:
-        """Global layer index of each stacked block position [P, v, M]
-        (one structural period: every layer of a cell is alike)."""
+        """Global layer index of each stacked block position [P, v, M,
+        period]: position ``(d, c, mi)`` of ``params["blocks"][j]``."""
         lay = self.spec.layout
-        if lay.period != 1:
-            raise ValueError("a cell's layers must all be of one kind")
-        out = np.zeros((lay.P, lay.v, lay.M), np.int64)
-        for d in range(lay.P):
-            for c in range(lay.v):
-                for j in range(lay.M):
-                    out[d, c, j] = lay.global_idx(d, c, j)
+        out = np.zeros((lay.P, lay.v, lay.M, lay.period), np.int64)
+        for d, c, mi, j in np.ndindex(out.shape):
+            out[d, c, mi, j] = lay.global_idx(d, c, mi * lay.period + j)
         return out
+
+    def _leaf_index(self) -> list:
+        """For each global layer, {path: (i, shape, init, dtype)}."""
+        m = self.model
+        return [{p: (i, s, init, dt) for i, (p, s, init, dt) in
+                 enumerate(M.layer_leaves(m, g))} for g in range(m.layers)]
 
     def _make_placer(self):
         m = self.model
-        leaves = {p: (i, s, init, dt) for i, (p, s, init, dt) in
-                  enumerate(M.layer_leaves(m))}
+        leaves = self._leaf_index()
         shared = {p: (i, s, init, dt) for i, (p, s, init, dt) in
                   enumerate(M.shared_leaves(m))}
         gidx = self.block_layers()
@@ -119,12 +109,17 @@ class Program:
         def make(seed):
             key0 = W.base_key(seed)
 
-            def block(path, struct):
-                i, s, init, dt = leaves[path]
-                _same(path, struct.shape[3:], struct.dtype, s, dt)
-                arr = jnp.stack([W.leaf(key0, int(g), i, s, init, dt)
-                                 for g in gidx.ravel()])
-                return arr.reshape(struct.shape)
+            def block(j, path, struct):
+                arr = []
+                for g in gidx[..., j].ravel():
+                    if path not in leaves[g]:
+                        raise ValueError(
+                            f"{path}: the program holds it in layer {g}, "
+                            f"the benchmark does not describe it there")
+                    i, s, init, dt = leaves[g][path]
+                    _same(path, struct.shape[3:], struct.dtype, s, dt)
+                    arr.append(W.leaf(key0, int(g), i, s, init, dt))
+                return jnp.stack(arr).reshape(struct.shape)
 
             def one_shared(path, struct):
                 i, s, init, dt = shared[path]
@@ -132,8 +127,8 @@ class Program:
                 return W.leaf(key0, W.SHARED, i, s, init, dt)
 
             out = {"blocks": [jax.tree_util.tree_map_with_path(
-                lambda p, a: block(_path(p), a), b)
-                for b in params_s["blocks"]]}
+                lambda p, a, j=j: block(j, _path(p), a), b)
+                for j, b in enumerate(params_s["blocks"])]}
             for k in params_s:
                 if k != "blocks":
                     out[k] = jax.tree_util.tree_map_with_path(
@@ -163,10 +158,10 @@ class Program:
         """{(layer, path): float} from a tree of per-position norms."""
         gidx = self.block_layers()
         out = {}
-        for b in tree["blocks"]:
+        for j, b in enumerate(tree["blocks"]):
             for p, a in jax.tree_util.tree_flatten_with_path(b)[0]:
                 a = np.asarray(a)
-                for pos, g in np.ndenumerate(gidx):
+                for pos, g in np.ndenumerate(gidx[..., j]):
                     out[(int(g), _path(p))] = float(a[pos])
         for k in tree:
             if k != "blocks":
@@ -200,19 +195,19 @@ class Program:
         AdamW received (``mu / (1 - beta1)``), per (layer, leaf)."""
         b1 = self.traffic["optimizer"]["beta1"]
         m = self.model
-        index = {p: i for i, (p, *_) in enumerate(M.layer_leaves(m))}
+        index = self._leaf_index()
         sindex = {p: i for i, (p, *_) in enumerate(M.shared_leaves(m))}
         gidx = self.block_layers()
 
         def fn(mu, s):
             key0 = W.base_key(s)
             out = {}
-            for b in mu["blocks"]:
+            for j, b in enumerate(mu["blocks"]):
                 for p, a in jax.tree_util.tree_flatten_with_path(b)[0]:
                     path = _path(p)
-                    for pos, g in np.ndenumerate(gidx):
+                    for pos, g in np.ndenumerate(gidx[..., j]):
                         out[f"{int(g)}|{path}"] = W.sketch(
-                            key0, int(g), index[path],
+                            key0, int(g), index[g][path][0],
                             a[pos].astype(jnp.float32) / (1.0 - b1))
             for k in mu:
                 if k != "blocks":

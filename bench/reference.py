@@ -1,6 +1,9 @@
-"""Plain float32 reference of a training cell: the model's equations, the
-loss, the gradients and AdamW, written from the published descriptions
-and imported from nothing of the program.
+"""Plain float32 reference of a training cell: the model's equations (its
+architecture module's, ``bench/arch/``), the loss, the gradients and
+AdamW, written from the published descriptions and imported from nothing
+of the program.  Layer ``g`` runs the equation and leaves that the module
+gives for ``g``; one forward and one pullback are compiled for each kind
+of layer.
 
 It runs layer by layer: the forward keeps each layer's input, the
 backward recomputes one layer at a time under ``jax.vjp``, so that the
@@ -32,111 +35,14 @@ from jax.sharding import SingleDeviceSharding
 
 from bench import model as M
 from bench import weights as W
+from bench.arch.common import F8_MAX, _q8
 from bench.synthetic import SyntheticLM
-
-HI = jax.lax.Precision.HIGHEST
-F8_MAX = 448.0
-
-
-def _q8(x, scale=None):
-    """float8_e4m3fn with a per-tensor scale (the tensor's own amax, or a
-    fixed ``scale``); the gradient passes straight through."""
-    s = jnp.maximum(jnp.max(jnp.abs(x)), 1e-30) / F8_MAX \
-        if scale is None else scale
-    # the barrier keeps the float8 value: a compiler that may keep excess
-    # precision is free to drop a cast pair inside one fusion
-    q = jax.lax.optimization_barrier((x / s).astype(jnp.float8_e4m3fn))
-    q = q.astype(jnp.float32) * s
-    return x + jax.lax.stop_gradient(q - x)
-
-
-def _mm(a, b, quant):
-    if quant:
-        a, b = _q8(a), _q8(b)
-    return jnp.matmul(a, b, precision=HI)
 
 
 def _scales(p):
     """Per-leaf float8 scales, fixed from the weights as first made."""
     return {k: jnp.maximum(jnp.max(jnp.abs(v)), 1e-30) / F8_MAX
             for k, v in p.items()}
-
-
-def _qp(p, scales):
-    """The weights as the forward reads them: in the control, each leaf
-    held in float8 on the grid of its fixed scale (the float32 master
-    beside it takes the update, as the program keeps one beside its
-    bfloat16 weights)."""
-    if scales is None:
-        return p
-    return {k: _q8(v, scales[k]) for k, v in p.items()}
-
-
-def _rmsnorm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def _rope(x, theta):
-    """Rotary embedding, rotate-half form; x [B, S, H, hd]."""
-    S, hd = x.shape[1], x.shape[-1]
-    inv = theta ** (-jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None] * inv       # [S, hd/2]
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _causal_attention(q, k, v, group=8):
-    """softmax(q k^T / sqrt(hd) + causal mask) v, a few heads at a time
-    (each group recomputed in the backward) so the scores fit."""
-    B, S, H, hd = q.shape
-    G = k.shape[2]
-    k = jnp.repeat(k, H // G, axis=2)
-    v = jnp.repeat(v, H // G, axis=2)
-    mask = jnp.tril(jnp.ones((S, S), bool))
-
-    @jax.checkpoint
-    def heads(qkv):
-        qh, kh, vh = qkv                                    # [B, S, g, hd]
-        s = jnp.einsum("bsgd,btgd->bgst", qh, kh, precision=HI) / math.sqrt(hd)
-        s = jnp.where(mask, s, -jnp.inf)
-        p = jax.nn.softmax(s, axis=-1)
-        return jnp.einsum("bgst,btgd->bsgd", p, vh, precision=HI)
-
-    g = min(group, H)
-    split = lambda a: a.reshape(B, S, H // g, g, hd).transpose(2, 0, 1, 3, 4)  # noqa: E731
-    out = jax.lax.map(heads, (split(q), split(k), split(v)))
-    return out.transpose(1, 2, 0, 3, 4).reshape(B, S, H * hd)
-
-
-def attn_layer(m: M.Model, p, x, scales):
-    B, S, d = x.shape
-    p, quant = _qp(p, scales), scales is not None
-    h = _rmsnorm(x, p["norm1.scale"], m.eps)
-    q = _mm(h, p["attn.wq"], quant).reshape(B, S, m.heads, m.hd)
-    k = _mm(h, p["attn.wk"], quant).reshape(B, S, m.kv_heads, m.hd)
-    v = _mm(h, p["attn.wv"], quant).reshape(B, S, m.kv_heads, m.hd)
-    o = _causal_attention(_rope(q, m.rope_theta), _rope(k, m.rope_theta), v)
-    x = x + _mm(o, p["attn.wo"], quant)
-    h = _rmsnorm(x, p["norm2.scale"], m.eps)
-    a = jax.nn.silu(_mm(h, p["mlp.wg"], quant)) * _mm(h, p["mlp.wi"], quant)
-    return x + _mm(a, p["mlp.wo"], quant)
-
-
-def embed(m: M.Model, table, tok, scale=None):
-    if scale is not None:
-        table = _q8(table, scale)
-    return table[tok] * math.sqrt(m.d)
-
-
-def head_loss(m: M.Model, s, x, labels, scales):
-    s, quant = _qp(s, scales), scales is not None
-    h = _rmsnorm(x, s["final_norm.scale"], m.eps)
-    w = s["embed.head"] if "embed.head" in s else s["embed.tokens"].T
-    logits = _mm(h, w, quant)
-    lse = jax.nn.logsumexp(logits, -1)
-    gold = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-    return jnp.mean(lse - gold)
 
 
 def lr_at(o: dict, step: int) -> float:
@@ -162,7 +68,7 @@ def _adam(p, g, mu, nu, *, lr, clip, step, o, dec):
     return p - lr * u, mu, nu
 
 
-def train(m: M.Model, traffic: dict, seed: int, devices, *,
+def train(m, traffic: dict, seed: int, devices, *,
           mode="f32") -> dict:
     """Runs the cell's first ``traffic["check_steps"]`` steps and returns
     the readings that the check compares: ``losses``, ``grad`` (norm of
@@ -175,13 +81,15 @@ def train(m: M.Model, traffic: dict, seed: int, devices, *,
     dev = [devices[g * len(devices) // L] for g in range(L)]
     first, last = devices[0], dev[-1]
     key0 = W.base_key(W.seed32(seed))
-    lleaves, sleaves = M.layer_leaves(m), M.shared_leaves(m)
+    mod = M.arch(m)
+    lleaves = [M.layer_leaves(m, g) for g in range(L)]
+    sleaves = M.shared_leaves(m)
 
     def make_layer(g):
         """Layer ``g``'s weights, made on the device that holds it."""
         return jax.jit(lambda: {
             p: W.leaf(key0, g, i, s, init, dt).astype(jnp.float32)
-            for i, (p, s, init, dt) in enumerate(lleaves)},
+            for i, (p, s, init, dt) in enumerate(lleaves[g])},
             out_shardings=SingleDeviceSharding(dev[g]))()
 
     def make_shared():
@@ -190,14 +98,27 @@ def train(m: M.Model, traffic: dict, seed: int, devices, *,
             for i, (p, s, init, dt) in enumerate(sleaves)},
             out_shardings=SingleDeviceSharding(first))()
 
-    fwd = jax.jit(lambda p, x, sc: attn_layer(m, p, x, sc))
-    bwd = jax.jit(lambda p, x, gy, sc: jax.vjp(
-        lambda p, x: attn_layer(m, p, x, sc), p, x)[1](gy))
-    emb = jax.jit(lambda t, tok, sc: embed(m, t, tok, sc))
+    def pair(g0):
+        """One jitted forward and pullback for the layers of ``g0``'s
+        kind, which share its equation."""
+        def f(p, x, sc):
+            return mod.layer(m, g0, p, x, sc)
+        return jax.jit(f), jax.jit(lambda p, x, gy, sc: jax.vjp(
+            lambda p, x: f(p, x, sc), p, x)[1](gy))
+
+    kinds = [mod.layer_kind(m, g) for g in range(L)]
+    pairs = {}
+    for g, k in enumerate(kinds):
+        if k not in pairs:
+            pairs[k] = pair(g)
+    fwd = [pairs[k][0] for k in kinds]
+    bwd = [pairs[k][1] for k in kinds]
+    emb = jax.jit(lambda t, tok, sc: mod.embed(m, t, tok, sc))
     emb_bwd = jax.jit(lambda t, tok, gx, sc: jax.vjp(
-        lambda t: embed(m, t, tok, sc), t)[1](gx)[0])
+        lambda t: mod.embed(m, t, tok, sc), t)[1](gx)[0])
     head = jax.jit(jax.value_and_grad(
-        lambda s, x, lab, sc: head_loss(m, s, x, lab, sc), argnums=(0, 1)))
+        lambda s, x, lab, sc: mod.head_loss(m, s, x, lab, sc),
+        argnums=(0, 1)))
     onto = jax.jit(lambda p, sc: {k: _q8(v, sc[k]) for k, v in p.items()})
     add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
                   donate_argnums=(0,))
@@ -227,7 +148,7 @@ def train(m: M.Model, traffic: dict, seed: int, devices, *,
 
     def adam_tree(p, gr, mu_, nu_, lr, clip, step):
         out = {k: _adam_jit(p[k], gr[k], mu_[k], nu_[k], lr, clip, step,
-                            M.decays(k, p[k].shape)) for k in p}
+                            M.decays(m, k, p[k].shape)) for k in p}
         return ({k: v[0] for k, v in out.items()},
                 {k: v[1] for k, v in out.items()},
                 {k: v[2] for k, v in out.items()})
@@ -258,12 +179,12 @@ def train(m: M.Model, traffic: dict, seed: int, devices, *,
             for g in range(L):
                 x = jax.device_put(x, dev[g])
                 xs.append(x)
-                x = fwd(params[g], x, lsc[g])
+                x = fwd[g](params[g], x, lsc[g])
             lv, (g_s, gx) = head(s_last, jax.device_put(x, last),
                                  jax.device_put(tok[:, 1:], last), sc_last)
             loss += float(lv)
             for g in reversed(range(L)):
-                gp, gx = bwd(params[g], xs[g], jax.device_put(gx, dev[g]),
+                gp, gx = bwd[g](params[g], xs[g], jax.device_put(gx, dev[g]),
                              lsc[g])
                 gl[g] = gp if gl[g] is None else add(gl[g], gp)
             del xs
@@ -311,7 +232,7 @@ def _sketches(key0, layers, shared, c, lleaves, sleaves) -> dict:
     sk = jax.jit(W.sketch, static_argnums=(1, 2))
     out = {}
     for g, t in enumerate(layers):
-        for i, (k, *_) in enumerate(lleaves):
+        for i, (k, *_) in enumerate(lleaves[g]):
             out[(g, k)] = np.asarray(sk(key0, g, i, t[k])) * c
     for i, (k, *_) in enumerate(sleaves):
         out[(-1, k)] = np.asarray(sk(key0, W.SHARED, i, shared[k])) * c

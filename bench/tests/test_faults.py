@@ -13,7 +13,8 @@ import pytest
 from bench import check, harness
 from bench.program import Program
 
-ATTN = {"name": "tiny-attn", "hidden_size": 64, "intermediate_size": 128,
+ATTN = {"name": "tiny-attn", "arch": "dense_decoder",
+        "hidden_size": 64, "intermediate_size": 128,
         "num_attention_heads": 4, "num_key_value_heads": 4,
         "num_hidden_layers": 2, "vocab_size": 256, "rope_theta": 10000.0,
         "rms_norm_eps": 1e-6, "hidden_act": "silu",
